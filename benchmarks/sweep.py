@@ -137,7 +137,9 @@ def _run_items_packet(items: List[dict], procs: int) -> List[dict]:
     t0 = time.perf_counter()
     if procs and procs > 1:
         indexed = list(enumerate(items))
-        ctx = mp.get_context("fork" if sys.platform == "linux" else "spawn")
+        # spawn, never fork: the parent may hold an accelerator through JAX,
+        # and a forked child would inherit that state
+        ctx = mp.get_context("spawn")
         cells: List[dict] = [None] * len(items)  # type: ignore[list-item]
         with ctx.Pool(processes=procs) as pool:
             done = 0

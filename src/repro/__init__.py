@@ -4,7 +4,3 @@
 training/serving framework around it.
 """
 __version__ = "1.0.0"
-
-# NOTE: jax compat shims (repro/compat.py) are installed by the jax-facing
-# subpackages' __init__ modules, not here — importing the simulator core
-# (repro.core.canary) must stay jax-free and fast.

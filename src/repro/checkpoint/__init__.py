@@ -1,7 +1,3 @@
-from ..compat import patch_jax as _patch_jax
-
-_patch_jax()
-
 from .checkpointer import latest_step, restore_checkpoint, save_checkpoint
 
 __all__ = ["latest_step", "restore_checkpoint", "save_checkpoint"]

@@ -1,9 +1,5 @@
 """TPU-native adaptation of Canary: multi-root tree collectives over mesh
 axes with congestion-oracle block scheduling (DESIGN.md §4)."""
-from ...compat import patch_jax as _patch_jax
-
-_patch_jax()
-
 from .api import canary_allreduce_tree
 from .congestion import CongestionOracle, round_robin_roots, tree_link_load
 from .trees import (hierarchical_allreduce, multi_root_tree_allreduce,

@@ -18,6 +18,7 @@ from typing import Any, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from .congestion import CongestionOracle, round_robin_roots
 from .trees import (hierarchical_allreduce, multi_root_tree_allreduce,
@@ -63,15 +64,22 @@ def canary_allreduce_tree(grads: Any, *, axis_name: str, axis_size: int,
                           mode: str = "canary",
                           outer_axis: Optional[str] = None,
                           fixed_point: bool = False,
-                          fp_bits: int = 24) -> Any:
+                          fp_bits: int = 24,
+                          leaf_specs: Any = None) -> Any:
     """Allreduce every leaf of ``grads`` along ``axis_name`` (+``outer_axis``).
 
     mode: canary (multi-root trees) | ring (RS+AG) | hierarchical | psum.
+    ``leaf_specs``: a tree of PartitionSpecs matching ``grads``, how each
+    leaf is split over the mesh axes that are still automatic; the
+    fixed-point path reduces each device's part on its own. ``None``:
+    every leaf is replicated over those axes.
     """
     if roots is None:
         roots = round_robin_roots(num_blocks, axis_size)
+    if leaf_specs is None:
+        leaf_specs = jax.tree.map(lambda _: P(), grads)
 
-    def one(x):
+    def one(x, spec):
         if fixed_point and mode == "canary":
             from repro.kernels.ops import fixed_point_allreduce_wrap
             gmax = lax.pmax(jnp.max(jnp.abs(x.astype(jnp.float32))), axis_name)
@@ -82,8 +90,8 @@ def canary_allreduce_tree(grads: Any, *, axis_name: str, axis_size: int,
             return fixed_point_allreduce_wrap(
                 x, lambda q: _leaf_allreduce(q, axis_name, axis_size, roots,
                                              mode, outer_axis),
-                gmax, bits=fp_bits, world=world)
+                gmax, bits=fp_bits, world=world, spec=spec)
         return _leaf_allreduce(x, axis_name, axis_size, roots, mode,
                                outer_axis)
 
-    return jax.tree.map(one, grads)
+    return jax.tree.map(one, grads, leaf_specs)

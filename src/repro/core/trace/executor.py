@@ -4,7 +4,8 @@ Each reduce round of a :class:`~repro.core.trace.schedule.Schedule` is one
 segment-sum — every step's source buffers are stacked into a packet matrix
 and scatter-accumulated into per-destination slots by
 :func:`repro.kernels.packet_accum.packet_accumulate` (the MXU one-hot-matmul
-kernel the software-switch benchmarks use), exactly the per-switch
+kernel the software-switch benchmarks use, through its jitted wrapper so
+that each round shape compiles once), exactly the per-switch
 aggregation of §3.1.1. The broadcast phase replicates the root buffer down
 the mirrored tree (§3.1.2).
 
@@ -26,15 +27,14 @@ from typing import Sequence
 
 import jax.numpy as jnp
 
-from repro.kernels.fixedpoint import dequantize, quantize
-from repro.kernels.ops import fixed_point_scale
-from repro.kernels.packet_accum import accumulate_dtype, packet_accumulate
+from repro.kernels.ops import (dequantize_op, fixed_point_scale,
+                               packet_accumulate_op, quantize_op)
+from repro.kernels.packet_accum import accumulate_dtype
 
 from .schedule import Schedule
 
 
-def replay_block(schedule: Schedule, inputs: jnp.ndarray, *,
-                 interpret: bool = True) -> jnp.ndarray:
+def replay_block(schedule: Schedule, inputs: jnp.ndarray) -> jnp.ndarray:
     """Replay one block's schedule over per-host input rows.
 
     ``inputs``: ``(P, D)`` — row ``r`` is the contribution of
@@ -60,9 +60,8 @@ def replay_block(schedule: Schedule, inputs: jnp.ndarray, *,
             for src in step.srcs:
                 slot_ids.append(slot)
                 payloads.append(buffers[src])
-        acc = packet_accumulate(jnp.asarray(slot_ids, jnp.int32),
-                                jnp.stack(payloads), len(rnd),
-                                interpret=interpret)
+        acc = packet_accumulate_op(jnp.asarray(slot_ids, jnp.int32),
+                                   jnp.stack(payloads), num_slots=len(rnd))
         for slot, step in enumerate(rnd):
             buffers[step.dst] = acc[slot]
 
@@ -72,20 +71,19 @@ def replay_block(schedule: Schedule, inputs: jnp.ndarray, *,
     return jnp.broadcast_to(total, (len(hosts),) + total.shape)
 
 
-def replay_app(schedules: Sequence[Schedule], inputs: jnp.ndarray, *,
-               interpret: bool = True) -> jnp.ndarray:
+def replay_app(schedules: Sequence[Schedule], inputs: jnp.ndarray
+               ) -> jnp.ndarray:
     """Replay a whole app: ``inputs`` is ``(P, B, D)`` (one row of blocks per
     participant, in ``schedules[b].hosts`` order); returns ``(P, B, D)``."""
     if inputs.shape[1] != len(schedules):
         raise ValueError(f"inputs has {inputs.shape[1]} blocks for "
                          f"{len(schedules)} schedules")
-    outs = [replay_block(s, inputs[:, b], interpret=interpret)
-            for b, s in enumerate(schedules)]
+    outs = [replay_block(s, inputs[:, b]) for b, s in enumerate(schedules)]
     return jnp.stack(outs, axis=1)
 
 
 def fixed_point_replay(schedules: Sequence[Schedule], x: jnp.ndarray, *,
-                       bits: int = 24, interpret: bool = True):
+                       bits: int = 24):
     """Fixed-point replay: quantize -> int32 tree accumulation -> dequantize.
 
     ``x``: ``(P, B, D)`` float inputs. Returns ``(result, q_result)`` where
@@ -98,9 +96,9 @@ def fixed_point_replay(schedules: Sequence[Schedule], x: jnp.ndarray, *,
     """
     gmax = jnp.max(jnp.abs(x.astype(jnp.float32)))
     scale = fixed_point_scale(gmax, bits=bits, world=x.shape[0])
-    q = quantize(x, scale, interpret=interpret)
-    q_result = replay_app(schedules, q, interpret=interpret)
-    return dequantize(q_result, scale, interpret=interpret), q_result
+    q = quantize_op(x, scale)
+    q_result = replay_app(schedules, q)
+    return dequantize_op(q_result, scale), q_result
 
 
 def reference_allreduce(x: jnp.ndarray) -> jnp.ndarray:

@@ -1,7 +1,3 @@
-from ..compat import patch_jax as _patch_jax
-
-_patch_jax()
-
 from .synthetic import DataConfig, batch_at, iterate
 
 __all__ = ["DataConfig", "batch_at", "iterate"]

@@ -1,7 +1,3 @@
-from ..compat import patch_jax as _patch_jax
-
-_patch_jax()
-
 # OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.

@@ -7,7 +7,8 @@ associative, so a Canary-style *dynamic* tree produces bit-identical sums no
 matter which tree shape each block took.
 
 VMEM tiling: elementwise over (8k, 128)-aligned tiles; the scalar scale rides
-in SMEM. Kernels are validated in interpret mode against ``ref.py``.
+in SMEM. Kernels are validated in interpret mode against ``ref.py`` and
+compile for the TPU (``tests/kernels/test_tpu_compile.py``).
 """
 from __future__ import annotations
 
@@ -17,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import mode
 
 TILE_ROWS = 256
 TILE_COLS = 128
@@ -31,7 +34,7 @@ def _dequant_kernel(scale_ref, q_ref, o_ref):
     o_ref[...] = q_ref[...].astype(jnp.float32) / scale_ref[0]
 
 
-def quantize(x: jnp.ndarray, scale, *, interpret: bool = True) -> jnp.ndarray:
+def quantize(x: jnp.ndarray, scale) -> jnp.ndarray:
     """Elementwise fixed-point quantization via a tiled Pallas kernel."""
     orig_shape = x.shape
     flat = x.reshape(-1)
@@ -52,12 +55,12 @@ def quantize(x: jnp.ndarray, scale, *, interpret: bool = True) -> jnp.ndarray:
         ],
         out_specs=pl.BlockSpec((TILE_ROWS, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((padded_rows, cols), jnp.int32),
-        interpret=interpret,
+        interpret=mode.interpret(),
     )(scale_arr, x2)
     return out.reshape(-1)[:n].reshape(orig_shape)
 
 
-def dequantize(q: jnp.ndarray, scale, *, interpret: bool = True) -> jnp.ndarray:
+def dequantize(q: jnp.ndarray, scale) -> jnp.ndarray:
     orig_shape = q.shape
     flat = q.reshape(-1)
     n = flat.shape[0]
@@ -77,6 +80,6 @@ def dequantize(q: jnp.ndarray, scale, *, interpret: bool = True) -> jnp.ndarray:
         ],
         out_specs=pl.BlockSpec((TILE_ROWS, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((padded_rows, cols), jnp.float32),
-        interpret=interpret,
+        interpret=mode.interpret(),
     )(scale_arr, q2)
     return out.reshape(-1)[:n].reshape(orig_shape)

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import mode
+
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
 
@@ -68,8 +70,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, bq: int = DEFAULT_BQ,
-                    bk: int = DEFAULT_BK, interpret: bool = True
-                    ) -> jnp.ndarray:
+                    bk: int = DEFAULT_BK) -> jnp.ndarray:
     """q: (B, H, S, D); k/v: (B, KV, S, D) with H % KV == 0 -> (B, H, S, D)."""
     B, H, S, D = q.shape
     KV = k.shape[1]
@@ -100,5 +101,5 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=mode.interpret(),
     )(q, k, v)

@@ -29,6 +29,11 @@ _WARNED_DTYPES: set = set()
 def parse_collective_bytes(hlo_text: str) -> Dict[str, float]:
     """Sum result-shape bytes of every collective op in the optimized HLO.
 
+    XLA's combiner passes merge collectives into tuple-shaped ops, so every
+    element of a tuple result counts, and ``per_op_count`` counts the arrays
+    a collective carries, not the ops: neither number depends on how much
+    the combiner merged.
+
     all-reduce moves ~2x its payload per device (reduce + broadcast phases /
     ring equivalents); the others move ~1x their result. The returned
     ``total_link_bytes`` applies those multipliers — the §Roofline collective
@@ -44,29 +49,29 @@ def parse_collective_bytes(hlo_text: str) -> Dict[str, float]:
     count = {k: 0 for k in _COLLECTIVES}
     unknown: Dict[str, int] = {}
     # e.g.:  %all-reduce.1 = bf16[1024,512]{1,0} all-reduce(...)
-    shape_re = re.compile(
-        r"=\s+(?:\()?([a-z0-9]+)\[([0-9,]*)\][^ ]*\s+([a-z\-]+)")
+    #        %all-reduce.2 = (f32[512,256]{1,0}, f32[256]{0}) all-reduce(...)
+    array_re = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
     for line in hlo_text.splitlines():
         hit = None
         for c in _COLLECTIVES:
-            if f" {c}(" in line or f" {c}-start(" in line:
-                hit = c
+            for op in (f" {c}(", f" {c}-start("):
+                if op in line:
+                    hit, result = c, line.split(op, 1)[0]
+                    break
+            if hit:
                 break
-        if hit is None:
+        if hit is None or "=" not in result:
             continue
-        m = shape_re.search(line)
-        if not m:
-            continue
-        dtype, dims, _ = m.groups()
-        size = _DTYPE_BYTES.get(dtype)
-        if size is None:
-            size = 4
-            unknown[dtype] = unknown.get(dtype, 0) + 1
-        for d in dims.split(","):
-            if d:
-                size *= int(d)
-        out[hit] += size
-        count[hit] += 1
+        for dtype, dims in array_re.findall(result.split("=", 1)[1]):
+            size = _DTYPE_BYTES.get(dtype)
+            if size is None:
+                size = 4
+                unknown[dtype] = unknown.get(dtype, 0) + 1
+            for d in dims.split(","):
+                if d:
+                    size *= int(d)
+            out[hit] += size
+            count[hit] += 1
     for dtype in unknown:
         if dtype not in _WARNED_DTYPES:
             _WARNED_DTYPES.add(dtype)

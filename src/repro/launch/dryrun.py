@@ -93,8 +93,7 @@ def build_dryrun(arch: str, shape_name: str, mesh, grad_sync: str = "auto",
                          if cfg.param_count() > 1e11 else "float32")
         tc = TrainConfig(model=cfg, optimizer=oc, grad_sync=grad_sync,
                          microbatches=microbatches)
-        step = make_train_step(tc, mesh=mesh, dp_axes=dp_axes,
-                               model_axis=model_axis)
+        step = make_train_step(tc, mesh=mesh, dp_axes=dp_axes)
         opt_shapes = jax.eval_shape(lambda p: adamw_init(p, oc),
                                     params_shapes)
         from repro.optim import AdamWState

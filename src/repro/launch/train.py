@@ -15,18 +15,20 @@ from __future__ import annotations
 import argparse
 import json
 import os
-from typing import Optional
+from typing import Dict, List, Optional
 
 import jax
 
 from repro.data import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_config
 from repro.optim import AdamWConfig, cosine_with_warmup
 from repro.parallel.context import ParallelContext, parallel_context
 from repro.train import TrainConfig, Trainer, TrainerConfig
 
 
-def main(argv: Optional[list] = None) -> None:
+def main(argv: Optional[list] = None) -> List[Dict[str, float]]:
+    """Train as the arguments say; returns the per-step history."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--variant", default="smoke", choices=["smoke", "full"])
@@ -47,6 +49,7 @@ def main(argv: Optional[list] = None) -> None:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--history-out", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, args.variant)
     dp = args.data_parallel or max(1, len(jax.devices())
@@ -77,6 +80,7 @@ def main(argv: Optional[list] = None) -> None:
         os.makedirs(os.path.dirname(args.history_out) or ".", exist_ok=True)
         with open(args.history_out, "w") as f:
             json.dump(history, f)
+    return history
 
 
 if __name__ == "__main__":

@@ -5,10 +5,7 @@ dataclasses + registry) and import eagerly. The jax-backed model functions
 (``forward``, ``init_params``, ...) load lazily on first attribute access
 (PEP 562) so that config-only consumers — notably the simulator-side
 workload compiler (``repro.core.workload``), which turns ``ModelConfig``s
-into gradient traffic — never pull jax into the process. The
-``repro.compat`` jax shims install right before the first lazy load (and at
-``repro.models.transformer`` import, for direct imports), preserving the
-patch-before-use ordering the eager ``__init__`` used to provide.
+into gradient traffic — never pull jax into the process.
 """
 from .config import ModelConfig
 from .registry import get_config, list_archs
@@ -23,8 +20,6 @@ __all__ = ["ModelConfig", "decode_step", "forward", "get_config",
 
 def __getattr__(name: str):
     if name in _LAZY_TRANSFORMER:
-        from ..compat import patch_jax
-        patch_jax()
         from . import transformer
         return getattr(transformer, name)
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -6,10 +6,6 @@ helpers. Shapes follow the (batch, seq, heads, head_dim) convention.
 """
 from __future__ import annotations
 
-from repro.compat import patch_jax as _patch_jax
-
-_patch_jax()  # repro.models.__init__ is lazy; direct imports land here first
-
 import dataclasses
 from typing import Dict, Optional, Tuple
 

@@ -7,10 +7,6 @@ fixed at 1 (B/C shared across heads), matching Mamba-2's default.
 """
 from __future__ import annotations
 
-from repro.compat import patch_jax as _patch_jax
-
-_patch_jax()  # repro.models.__init__ is lazy; direct imports land here first
-
 from typing import Dict, Tuple
 
 import jax

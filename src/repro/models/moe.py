@@ -18,10 +18,6 @@ plus a Switch-style load-balancing auxiliary loss.
 """
 from __future__ import annotations
 
-from repro.compat import patch_jax as _patch_jax
-
-_patch_jax()  # repro.models.__init__ is lazy; direct imports land here first
-
 from typing import Dict, Optional, Tuple
 
 import jax

@@ -16,10 +16,6 @@ Everything is pure-functional: ``init_params`` builds the pytree,
 """
 from __future__ import annotations
 
-from repro.compat import patch_jax as _patch_jax
-
-_patch_jax()  # repro.models.__init__ is lazy; direct imports land here first
-
 import math
 from typing import Any, Dict, List, Optional, Tuple
 

@@ -1,7 +1,3 @@
-from ..compat import patch_jax as _patch_jax
-
-_patch_jax()
-
 from .adamw import AdamWConfig, AdamWState, global_norm, init, update
 from .schedules import cosine_with_warmup, linear_warmup_constant
 

@@ -1,7 +1,3 @@
-from ..compat import patch_jax as _patch_jax
-
-_patch_jax()
-
 from .engine import Engine, ServeConfig, make_serve_step
 
 __all__ = ["Engine", "ServeConfig", "make_serve_step"]

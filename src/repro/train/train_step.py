@@ -38,6 +38,7 @@ from repro.models.config import ModelConfig
 from repro.optim import AdamWConfig, AdamWState
 from repro.optim import init as adamw_init
 from repro.optim import update as adamw_update
+from repro.parallel.sharding import param_specs
 from .losses import cross_entropy
 
 EXPLICIT_MODES = ("canary", "ring", "hierarchical", "canary_fp")
@@ -90,48 +91,45 @@ def make_loss_fn(tc: TrainConfig, constrain: str = "full") -> Callable:
     return loss_fn
 
 
-def make_train_step(tc: TrainConfig, mesh: Optional[Mesh] = None,
-                    dp_axes: Tuple[str, ...] = ("data",),
-                    model_axis: str = "model") -> Callable:
-    """Returns train_step(params, opt_state, batch) -> (params, opt_state,
-    metrics). jit/lower is the caller's job (launcher / dryrun)."""
+def make_grads_fn(tc: TrainConfig, mesh: Optional[Mesh] = None,
+                  dp_axes: Tuple[str, ...] = ("data",)) -> Callable:
+    """Returns grads_fn(params, batch) -> (grads, metrics): the gradients of
+    the mean loss over the global batch, synchronized over the data axes by
+    ``tc.grad_sync``, and the step's metrics."""
     loss_fn = make_loss_fn(tc, constrain="full" if tc.grad_sync == "auto"
                            else "none")
 
     if tc.grad_sync == "auto":
-        def train_step(params, opt_state, batch):
+        def auto_grads(params, batch):
             k = tc.microbatches
             if k <= 1:
                 (loss, metrics), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(params, batch)
-            else:
-                mb = jax.tree.map(
-                    lambda v: v.reshape((k, v.shape[0] // k) + v.shape[1:]),
-                    batch)
+                return grads, metrics
+            mb = jax.tree.map(
+                lambda v: v.reshape((k, v.shape[0] // k) + v.shape[1:]),
+                batch)
 
-                def mb_step(acc, one):
-                    g_acc, m_acc = acc
-                    (loss, metrics), grads = jax.value_and_grad(
-                        loss_fn, has_aux=True)(params, one)
-                    g_acc = jax.tree.map(
-                        lambda a, g: a + g.astype(a.dtype), g_acc, grads)
-                    m_acc = jax.tree.map(lambda a, m: a + m / k, m_acc,
-                                         metrics)
-                    return (g_acc, m_acc), None
+            def mb_step(acc, one):
+                g_acc, m_acc = acc
+                (loss, metrics), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params, one)
+                g_acc = jax.tree.map(
+                    lambda a, g: a + g.astype(a.dtype), g_acc, grads)
+                m_acc = jax.tree.map(lambda a, m: a + m / k, m_acc,
+                                     metrics)
+                return (g_acc, m_acc), None
 
-                g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
-                                  params)
-                m0 = {"loss": jnp.zeros((), jnp.float32),
-                      "accuracy": jnp.zeros((), jnp.float32),
-                      "aux_loss": jnp.zeros((), jnp.float32)}
-                (grads, metrics), _ = jax.lax.scan(mb_step, (g0, m0), mb)
-                grads = jax.tree.map(lambda g, p: (g / k).astype(p.dtype),
-                                     grads, params)
-            params, opt_state, om = adamw_update(grads, opt_state, params,
-                                                 tc.optimizer)
-            metrics.update(om)
-            return params, opt_state, metrics
-        return train_step
+            g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
+                              params)
+            m0 = {"loss": jnp.zeros((), jnp.float32),
+                  "accuracy": jnp.zeros((), jnp.float32),
+                  "aux_loss": jnp.zeros((), jnp.float32)}
+            (grads, metrics), _ = jax.lax.scan(mb_step, (g0, m0), mb)
+            grads = jax.tree.map(lambda g, p: (g / k).astype(p.dtype),
+                                 grads, params)
+            return grads, metrics
+        return auto_grads
 
     if tc.grad_sync not in EXPLICIT_MODES:
         raise ValueError(f"unknown grad_sync {tc.grad_sync}")
@@ -144,9 +142,10 @@ def make_train_step(tc: TrainConfig, mesh: Optional[Mesh] = None,
     mode = {"canary": "canary", "canary_fp": "canary", "ring": "ring",
             "hierarchical": "hierarchical"}[tc.grad_sync]
     fixed_point = tc.grad_sync == "canary_fp"
+    model_axis = next((a for a in mesh.axis_names if a not in dp_axes), None)
     roots = list(tc.canary_roots) if tc.canary_roots is not None else None
 
-    def grads_fn(params, batch):
+    def local_grads(params, batch):
         """Per-data-shard gradients + explicit Canary reduction."""
         import dataclasses as _dc
         from repro.parallel.context import (get_parallel_context,
@@ -162,10 +161,14 @@ def make_train_step(tc: TrainConfig, mesh: Optional[Mesh] = None,
         else:
             (loss, metrics), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, batch)
+        # the grads follow the params: replicated over the data axes,
+        # split over the model axis as the Trainer places them
+        specs = param_specs(grads, mesh, fsdp=None, model=model_axis,
+                            use_fsdp=False) if model_axis else None
         synced = canary_allreduce_tree(
             grads, axis_name=inner, axis_size=axis_size, roots=roots,
             num_blocks=tc.canary_blocks, mode=mode, outer_axis=outer,
-            fixed_point=fixed_point)
+            fixed_point=fixed_point, leaf_specs=specs)
         # average over the data parallelism degree
         dp = axis_size * (mesh.shape[outer] if outer else 1)
         synced = jax.tree.map(lambda g: g / dp, synced)
@@ -176,16 +179,27 @@ def make_train_step(tc: TrainConfig, mesh: Optional[Mesh] = None,
 
     batch_in_spec = P(dp_axes if len(dp_axes) > 1 else dp_axes[0])
 
-    def train_step(params, opt_state, batch):
-        sharded_grads = jax.shard_map(
-            grads_fn,
+    def explicit_grads(params, batch):
+        return jax.shard_map(
+            local_grads,
             mesh=mesh,
             in_specs=(P(), jax.tree.map(lambda _: batch_in_spec, batch)),
             out_specs=(P(), P()),
             axis_names=set(dp_axes),
             check_vma=False,
         )(params, batch)
-        grads, metrics = sharded_grads
+
+    return explicit_grads
+
+
+def make_train_step(tc: TrainConfig, mesh: Optional[Mesh] = None,
+                    dp_axes: Tuple[str, ...] = ("data",)) -> Callable:
+    """Returns train_step(params, opt_state, batch) -> (params, opt_state,
+    metrics). jit/lower is the caller's job (launcher / dryrun)."""
+    grads_fn = make_grads_fn(tc, mesh, dp_axes)
+
+    def train_step(params, opt_state, batch):
+        grads, metrics = grads_fn(params, batch)
         params, opt_state, om = adamw_update(grads, opt_state, params,
                                              tc.optimizer)
         metrics.update(om)

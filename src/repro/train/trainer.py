@@ -1,20 +1,25 @@
 """Training loop: data pipeline + train_step + congestion-oracle feedback +
-checkpointing. CPU-scale by design (the examples train ~10-100M-param reduced
-configs); the same code jit-lowers for the production meshes via launch/.
+checkpointing. Given a mesh, the Trainer places params, optimizer state and
+every batch on it with the rules of ``repro.parallel.sharding``; without one
+everything stays on the default device.
 """
 from __future__ import annotations
 
+import dataclasses
 import time
-from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional
+from dataclasses import dataclass
+from functools import partial
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core.collective import CongestionOracle
 from repro.data import DataConfig, batch_at
-from repro.optim import AdamWConfig
+from repro.optim import AdamWState
+from repro.parallel.sharding import batch_spec, param_shardings
 from .train_step import TrainConfig, init_train_state, make_train_step
 
 
@@ -29,6 +34,15 @@ class TrainerConfig:
     replan_every: int = 0     # >0: re-plan canary roots from oracle feedback
 
 
+def replan(tc: TrainConfig,
+           oracle: Optional[CongestionOracle]) -> TrainConfig:
+    """``tc`` with the oracle's current Canary roots, every other field
+    kept; ``tc`` itself when there is no oracle."""
+    if oracle is None:
+        return tc
+    return dataclasses.replace(tc, canary_roots=tuple(oracle.plan()))
+
+
 class Trainer:
     def __init__(self, cfg: TrainerConfig, mesh=None, dp_axes=("data",),
                  model_axis="model", seed: int = 0):
@@ -36,8 +50,24 @@ class Trainer:
         self.mesh = mesh
         self.dp_axes = dp_axes
         self.model_axis = model_axis
-        self.params, self.opt_state = init_train_state(
-            cfg.train, jax.random.PRNGKey(seed))
+        init = partial(init_train_state, cfg.train)
+        key = jax.random.PRNGKey(seed)
+        self.batch_sharding = None
+        if mesh is None:
+            self.params, self.opt_state = init(key)
+        else:
+            # explicit grad-sync modes reduce over the data axes themselves,
+            # so they need params replicated there; auto shards them (FSDP)
+            dp = dp_axes if len(dp_axes) > 1 else dp_axes[0]
+            p_shard = param_shardings(
+                jax.eval_shape(init, key)[0], mesh, fsdp=dp,
+                model=model_axis, use_fsdp=cfg.train.grad_sync == "auto")
+            state_shard = (p_shard, AdamWState(
+                step=NamedSharding(mesh, P()), m=p_shard, v=p_shard))
+            self.params, self.opt_state = jax.jit(
+                init, out_shardings=state_shard)(key)
+            self.batch_sharding = NamedSharding(
+                mesh, batch_spec(mesh, cfg.data.global_batch, dp))
         self.oracle: Optional[CongestionOracle] = None
         if cfg.train.grad_sync in ("canary", "canary_fp") and mesh is not None:
             self.oracle = CongestionOracle(
@@ -47,20 +77,16 @@ class Trainer:
         self.history: List[Dict[str, float]] = []
 
     def _build_step(self):
-        tc = self.cfg.train
-        if self.oracle is not None:
-            tc = TrainConfig(model=tc.model, optimizer=tc.optimizer,
-                             grad_sync=tc.grad_sync,
-                             canary_blocks=tc.canary_blocks,
-                             canary_roots=tuple(self.oracle.plan()),
-                             z_loss=tc.z_loss)
-        fn = make_train_step(tc, mesh=self.mesh, dp_axes=self.dp_axes,
-                             model_axis=self.model_axis)
+        tc = replan(self.cfg.train, self.oracle)
+        fn = make_train_step(tc, mesh=self.mesh, dp_axes=self.dp_axes)
         self.step_fn = jax.jit(fn, donate_argnums=(0, 1))
 
-    def _make_batch(self, step: int) -> Dict[str, jnp.ndarray]:
+    def make_batch(self, step: int) -> Dict[str, jnp.ndarray]:
+        """The batch of ``step``, split over the data axes of the mesh when
+        the Trainer has one."""
         np_batch = batch_at(self.cfg.data, step)
-        batch = {k: jnp.asarray(v) for k, v in np_batch.items()}
+        batch = {k: jax.device_put(v, self.batch_sharding)
+                 for k, v in np_batch.items()}
         mcfg = self.cfg.train.model
         B = self.cfg.data.global_batch
         if mcfg.frontend == "audio_stub":
@@ -74,7 +100,7 @@ class Trainer:
     def run(self) -> List[Dict[str, float]]:
         cfg = self.cfg
         for step in range(cfg.steps):
-            batch = self._make_batch(step)
+            batch = self.make_batch(step)
             t0 = time.perf_counter()
             self.params, self.opt_state, metrics = self.step_fn(
                 self.params, self.opt_state, batch)

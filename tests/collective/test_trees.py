@@ -96,6 +96,28 @@ got = jax.jit(jax.shard_map(
     check_vma=False))(xx)
 np.testing.assert_allclose(np.asarray(got), want2, rtol=1e-5, atol=1e-5)
 print("hierarchical ok")
+
+# 8) fixed-point canary with the model axis left automatic: each leaf is
+#    quantized and reduced as its spec splits it over "model"
+mesh3 = jax.make_mesh((4, 2), ("data", "model"),
+                      axis_types=(jax.sharding.AxisType.Auto,) * 2)
+x3 = jax.device_put(jax.random.normal(key, (4, 16, 32)),
+                    jax.sharding.NamedSharding(mesh3, P("data", "model")))
+want8 = np.broadcast_to(np.asarray(x3).sum(0, keepdims=True), (4, 16, 32))
+outs = []
+for roots in ((0, 1, 2, 3), (3, 2, 1, 0)):
+    got = jax.jit(jax.shard_map(
+        lambda t, rr=roots: canary_allreduce_tree(
+            {"a": t, "b": 2 * t}, axis_name="data", axis_size=4, roots=rr,
+            fixed_point=True, leaf_specs={"a": P(None, "model"), "b": P()}),
+        mesh=mesh3, in_specs=P("data"), out_specs=P("data"),
+        axis_names={"data"}, check_vma=False))(x3)
+    outs.append(jax.tree.map(np.asarray, got))
+for k, scale in (("a", 1), ("b", 2)):
+    np.testing.assert_array_equal(outs[0][k], outs[1][k])
+    np.testing.assert_allclose(outs[0][k], scale * want8, rtol=1e-3,
+                               atol=1e-3)
+print("fixed-point over an automatic model axis ok")
 print("ALL_OK")
 """
 

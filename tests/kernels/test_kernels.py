@@ -1,6 +1,7 @@
 """Per-kernel interpret-mode validation against the pure-jnp oracles,
-sweeping shapes and dtypes (pl.pallas_call + BlockSpec run on CPU via
-interpret=True; the kernel bodies are identical on TPU).
+sweeping shapes and dtypes (on the CPU the kernels run in the Pallas
+interpreter; the kernel bodies are identical on TPU, where they compile —
+see test_tpu_compile.py).
 """
 import jax
 import jax.numpy as jnp
@@ -87,6 +88,29 @@ def test_packet_accumulate_int32_matches_ref(n, d, slots):
     got = packet_accumulate(ids, pay, slots)
     want = packet_accumulate_ref(ids, pay, slots)
     assert got.dtype == jnp.int32 and want.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_packet_accumulate_int32_wraps_like_int32_addition():
+    """Full-range int32 payloads, negative and overflowing: the byte-split
+    accumulation must give exactly the wrapped int32 sum."""
+    n, d, slots = 300, 130, 3
+    ids = jax.random.randint(jax.random.PRNGKey(11), (n,), 0, slots)
+    info = jnp.iinfo(jnp.int32)
+    pay = jax.random.randint(jax.random.PRNGKey(12), (n, d), info.min,
+                             info.max, dtype=jnp.int32)
+    got = packet_accumulate(ids, pay, slots)
+    want = packet_accumulate_ref(ids, pay, slots)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
+def test_packet_accumulate_drops_out_of_range_ids(dtype):
+    """Ids outside [0, num_slots) contribute nothing, as in segment_sum."""
+    ids = jnp.array([0, -1, 2, 5, 9, 2, 1], jnp.int32)
+    pay = jnp.arange(7 * 4, dtype=dtype).reshape(7, 4)
+    got = packet_accumulate(ids, pay, 3)
+    want = packet_accumulate_ref(ids, pay, 3)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 

@@ -34,6 +34,19 @@ def test_parse_collective_bytes_categories():
     assert r["per_op_count"]["all-reduce"] == 2
 
 
+def test_parse_sums_every_element_of_a_tuple_collective():
+    """XLA's combiner merges gradient all-reduces into one tuple-shaped op:
+    every element counts, bytes and arrays alike."""
+    hlo = ("  %all-reduce.39 = (f32[512,256]{1,0}, f32[256]{0}, /*index=2*/"
+           "bf16[2,64,256]{2,1,0}) all-reduce(f32[512,256]{1,0} %a, "
+           "f32[256]{0} %b, bf16[2,64,256]{2,1,0} %c), to_apply=%add\n")
+    r = parse_collective_bytes(hlo)
+    assert r["per_op_bytes"]["all-reduce"] == (512 * 256 * 4 + 256 * 4
+                                              + 2 * 64 * 256 * 2)
+    assert r["per_op_count"]["all-reduce"] == 3
+    assert r["unknown_dtypes"] == {}
+
+
 def test_parse_ignores_non_collectives():
     r = parse_collective_bytes("%dot = f32[8,8]{1,0} dot(...)\n")
     assert r["total_link_bytes"] == 0

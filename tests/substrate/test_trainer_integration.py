@@ -84,6 +84,33 @@ def test_microbatched_step_matches_full_batch():
                                    rtol=1e-3, atol=1e-4)
 
 
+def test_replan_keeps_every_train_config_field():
+    """An oracle re-plan changes the Canary roots and nothing else of the
+    TrainConfig (microbatches, z_loss, ... survive), and a Trainer given a
+    mesh holds its params and batches on that mesh."""
+    import dataclasses
+
+    from jax.sharding import NamedSharding
+    from repro.launch.mesh import make_host_mesh
+    from repro.train.trainer import replan
+
+    mesh = make_host_mesh(1, 1)
+    cfg = get_config("llama3.2-1b", "smoke")
+    tc = TrainConfig(model=cfg, optimizer=AdamWConfig(lr=1e-3),
+                     grad_sync="canary", canary_blocks=8, microbatches=2,
+                     z_loss=1e-4)
+    data = DataConfig(vocab_size=cfg.vocab_size, global_batch=4, seq_len=16)
+    t = Trainer(TrainerConfig(train=tc, data=data, steps=0), mesh=mesh)
+    t.oracle.feedback(1.0)
+    assert replan(tc, t.oracle) == dataclasses.replace(
+        tc, canary_roots=tuple(t.oracle.plan()))
+    assert replan(tc, None) is tc
+    for leaf in jax.tree.leaves((t.params, t.opt_state,
+                                 t.make_batch(0))):
+        assert isinstance(leaf.sharding, NamedSharding)
+        assert leaf.sharding.mesh == mesh
+
+
 REPLAN_SCRIPT = r"""
 import os
 import jax
