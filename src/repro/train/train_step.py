@@ -62,6 +62,9 @@ def make_loss_fn(tc: TrainConfig, constrain: str = "full") -> Callable:
     — safe inside a data-manual shard_map), or 'none'."""
     cfg = tc.model
 
+    # the backward pass carries this scope as transpose(jvp(forward)), and
+    # remat's recompute as rematted_computation under it
+    @jax.named_scope("forward")
     def loss_fn(params, batch):
         from jax.sharding import NamedSharding
         from repro.parallel.context import get_parallel_context
@@ -165,13 +168,14 @@ def make_grads_fn(tc: TrainConfig, mesh: Optional[Mesh] = None,
         # split over the model axis as the Trainer places them
         specs = param_specs(grads, mesh, fsdp=None, model=model_axis,
                             use_fsdp=False) if model_axis else None
-        synced = canary_allreduce_tree(
-            grads, axis_name=inner, axis_size=axis_size, roots=roots,
-            num_blocks=tc.canary_blocks, mode=mode, outer_axis=outer,
-            fixed_point=fixed_point, leaf_specs=specs)
-        # average over the data parallelism degree
-        dp = axis_size * (mesh.shape[outer] if outer else 1)
-        synced = jax.tree.map(lambda g: g / dp, synced)
+        with jax.named_scope("grad_sync"):
+            synced = canary_allreduce_tree(
+                grads, axis_name=inner, axis_size=axis_size, roots=roots,
+                num_blocks=tc.canary_blocks, mode=mode, outer_axis=outer,
+                fixed_point=fixed_point, leaf_specs=specs)
+            # average over the data parallelism degree
+            dp = axis_size * (mesh.shape[outer] if outer else 1)
+            synced = jax.tree.map(lambda g: g / dp, synced)
         metrics = jax.tree.map(
             lambda m: jax.lax.pmean(jax.lax.pmean(m, inner), outer)
             if outer else jax.lax.pmean(m, inner), metrics)
@@ -200,8 +204,9 @@ def make_train_step(tc: TrainConfig, mesh: Optional[Mesh] = None,
 
     def train_step(params, opt_state, batch):
         grads, metrics = grads_fn(params, batch)
-        params, opt_state, om = adamw_update(grads, opt_state, params,
-                                             tc.optimizer)
+        with jax.named_scope("optimizer"):
+            params, opt_state, om = adamw_update(grads, opt_state, params,
+                                                 tc.optimizer)
         metrics.update(om)
         return params, opt_state, metrics
 

@@ -83,10 +83,11 @@ class Trainer:
 
     def make_batch(self, step: int) -> Dict[str, jnp.ndarray]:
         """The batch of ``step``, split over the data axes of the mesh when
-        the Trainer has one."""
-        np_batch = batch_at(self.cfg.data, step)
-        batch = {k: jax.device_put(v, self.batch_sharding)
-                 for k, v in np_batch.items()}
+        the Trainer has one. Runs under the host span ``make_batch``."""
+        with jax.profiler.TraceAnnotation("make_batch"):
+            np_batch = batch_at(self.cfg.data, step)
+            batch = {k: jax.device_put(v, self.batch_sharding)
+                     for k, v in np_batch.items()}
         mcfg = self.cfg.train.model
         B = self.cfg.data.global_batch
         if mcfg.frontend == "audio_stub":
