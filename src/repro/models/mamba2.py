@@ -40,10 +40,21 @@ def init_mamba2(key, cfg: ModelConfig, dtype) -> Params:
     }
 
 
-def _segsum(x: jnp.ndarray) -> jnp.ndarray:
-    """(..., T) -> (..., T, T): cumulative segment sums, -inf above diagonal."""
+def _chunk_cumsum(x: jnp.ndarray) -> jnp.ndarray:
+    """(..., T) -> (..., T): prefix sums along the last axis as one
+    triangular matmul, which the TPU runs on the MXU (a cumsum lowers to a
+    windowed sum on the VPU). HIGHEST keeps the f32 sums f32-accurate: by
+    default the TPU would round x to bf16, and the sums reach the hundreds."""
     T = x.shape[-1]
-    csum = jnp.cumsum(x, axis=-1)
+    U = jnp.triu(jnp.ones((T, T), jnp.float32))                  # U[s, l] = s <= l
+    return lax.dot_general(x, U, (((x.ndim - 1,), (0,)), ((), ())),
+                           precision=lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+
+
+def _segsum(csum: jnp.ndarray) -> jnp.ndarray:
+    """(..., T) prefix sums -> (..., T, T): segment sums, -inf above diagonal."""
+    T = csum.shape[-1]
     diff = csum[..., :, None] - csum[..., None, :]
     mask = jnp.tril(jnp.ones((T, T), dtype=bool), k=0)
     return jnp.where(mask, diff, -jnp.inf)
@@ -74,12 +85,13 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int,
     Bc = Bm.astype(f32).reshape(b, c, chunk, n)
     Cc = Cm.astype(f32).reshape(b, c, chunk, n)
 
+    A_cum = _chunk_cumsum(dAc)                                   # (b,h,c,q)
+
     # 1) intra-chunk (quadratic) term
-    L = jnp.exp(_segsum(dAc))                                    # (b,h,c,q,q)
+    L = jnp.exp(_segsum(A_cum))                                  # (b,h,c,q,q)
     Y_diag = jnp.einsum("bcln,bcsn,bhcls,bcshp->bclhp", Cc, Bc, L, xc)
 
     # 2) chunk end-states
-    A_cum = jnp.cumsum(dAc, axis=-1)                             # (b,h,c,q)
     decay_states = jnp.exp(A_cum[..., -1:] - A_cum)              # (b,h,c,q)
     states = jnp.einsum("bcln,bhcl,bclhp->bchpn", Bc, decay_states, xc)
 

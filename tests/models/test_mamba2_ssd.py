@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
 from repro.models import get_config, init_params
 from repro.models.mamba2 import (init_mamba2, mamba2_decode_step,
@@ -31,7 +32,8 @@ def ssd_reference(x, dt, A, Bm, Cm):
     return np.stack(ys, axis=1), st
 
 
-@pytest.mark.parametrize("s,chunk", [(16, 4), (32, 8), (24, 8), (64, 16)])
+@pytest.mark.parametrize("s,chunk", [(16, 4), (32, 8), (24, 8), (64, 16),
+                                     (512, 256)])
 @pytest.mark.parametrize("seed", [0, 1])
 def test_ssd_chunked_matches_recurrence(s, chunk, seed):
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
@@ -45,6 +47,123 @@ def test_ssd_chunked_matches_recurrence(s, chunk, seed):
     y_ref, st_ref = ssd_reference(x, dt, A, Bm, Cm)
     np.testing.assert_allclose(np.asarray(y), y_ref, rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(st), st_ref, rtol=2e-4, atol=2e-4)
+
+
+def ssd_chunked_cumsum(x, dt, A, Bm, Cm, chunk):
+    """The chunked SSD with its in-chunk prefix sums as ``jnp.cumsum``: the
+    formula the matmul form replaced, kept as an oracle."""
+    b, s, h, p = x.shape
+    n = Bm.shape[-1]
+    c = s // chunk
+    xd = x * dt[..., None]
+    dAc = (dt * A[None, None, :]).reshape(b, c, chunk, h).transpose(0, 3, 1, 2)
+    xc = xd.reshape(b, c, chunk, h, p)
+    Bc = Bm.reshape(b, c, chunk, n)
+    Cc = Cm.reshape(b, c, chunk, n)
+    A_cum = jnp.cumsum(dAc, axis=-1)
+    diff = A_cum[..., :, None] - A_cum[..., None, :]
+    L = jnp.exp(jnp.where(jnp.tril(jnp.ones((chunk, chunk), bool)), diff,
+                          -jnp.inf))
+    Y_diag = jnp.einsum("bcln,bcsn,bhcls,bcshp->bclhp", Cc, Bc, L, xc)
+    decay_states = jnp.exp(A_cum[..., -1:] - A_cum)
+    states = jnp.einsum("bcln,bhcl,bclhp->bchpn", Bc, decay_states, xc)
+
+    def step(st_in, inp):
+        dec, st_chunk = inp
+        return st_in * dec[..., None, None] + st_chunk, st_in
+
+    final_state, states_in = lax.scan(
+        step, jnp.zeros((b, h, p, n), x.dtype),
+        (jnp.exp(A_cum[..., -1]).transpose(2, 0, 1),
+         states.transpose(1, 0, 2, 3, 4)))
+    Y_off = jnp.einsum("bcln,bchpn,bhcl->bclhp", Cc,
+                       states_in.transpose(1, 0, 2, 3, 4), jnp.exp(A_cum))
+    return (Y_diag + Y_off).reshape(b, s, h, p), final_state
+
+
+def _published_inputs(seed, b, s, h, p, n):
+    """dt log-uniform in [1e-3, 1e-1] and A uniform in [-16, -1], as
+    Mamba-2's published initialisation draws them."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x = jax.random.normal(ks[0], (b, s, h, p))
+    dt = jnp.exp(jax.random.uniform(ks[1], (b, s, h), minval=np.log(1e-3),
+                                    maxval=np.log(1e-1)))
+    A = -jax.random.uniform(ks[2], (h,), minval=1.0, maxval=16.0)
+    Bm = jax.random.normal(ks[3], (b, s, n)) * 0.5
+    Cm = jax.random.normal(ks[4], (b, s, n)) * 0.5
+    return x, dt, A, Bm, Cm
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_ssd_matmul_prefix_sums_match_cumsum(seed):
+    """Forward and every input's gradient equal the cumsum formula at the
+    training chunk of 256."""
+    chunk = 256
+    args = _published_inputs(seed, 2, 512, 3, 4, 8)
+    wy = jax.random.normal(jax.random.PRNGKey(seed + 20), (2, 512, 3, 4))
+    ws = jax.random.normal(jax.random.PRNGKey(seed + 30), (2, 3, 4, 8))
+
+    def loss(fn):
+        def f(*a):
+            y, st = fn(*a, chunk)
+            return jnp.sum(y * wy) + jnp.sum(st * ws)
+        return f
+
+    y, st = ssd_chunked(*args, chunk)
+    y_ref, st_ref = ssd_chunked_cumsum(*args, chunk)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(st), np.asarray(st_ref),
+                               rtol=1e-5, atol=1e-5)
+    argnums = tuple(range(5))
+    grads = jax.grad(loss(ssd_chunked), argnums)(*args)
+    grads_ref = jax.grad(loss(ssd_chunked_cumsum), argnums)(*args)
+    # atol scales with the leaf: dt's gradient reaches about 20 and sums
+    # terms that cancel, so both forms sit up to 7e-5 from a float64 run.
+    for name, g, g_ref in zip(("x", "dt", "A", "Bm", "Cm"), grads, grads_ref):
+        g_ref = np.asarray(g_ref)
+        np.testing.assert_allclose(np.asarray(g), g_ref, rtol=1e-5,
+                                   atol=1e-5 * np.abs(g_ref).max(),
+                                   err_msg=name)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_ssd_prefix_sums_are_a_highest_precision_matmul():
+    """At the mamba2-130m cell's shapes, neither the SSD nor its gradient
+    holds a cumsum, and each prefix-sum matmul (an operand of shape
+    (chunk, chunk)) runs at HIGHEST precision: the CPU ignores precision,
+    so this is what keeps the TPU from rounding dt * A to bf16."""
+    b, s, h, p, n, chunk = 8, 2048, 24, 64, 128, 256
+    f32 = jnp.float32
+    shapes = (jax.ShapeDtypeStruct((b, s, h, p), f32),
+              jax.ShapeDtypeStruct((b, s, h), f32),
+              jax.ShapeDtypeStruct((h,), f32),
+              jax.ShapeDtypeStruct((b, s, n), f32),
+              jax.ShapeDtypeStruct((b, s, n), f32))
+
+    def fwd(*a):
+        return ssd_chunked(*a, chunk)
+
+    def grad(*a):
+        return jax.grad(lambda *a: sum(jnp.sum(o) for o in fwd(*a)),
+                        range(5))(*a)
+
+    for fn in (fwd, grad):
+        eqns = list(_eqns(jax.make_jaxpr(fn)(*shapes).jaxpr))
+        assert not [e for e in eqns if "cumsum" in e.primitive.name]
+        prefix = [e for e in eqns if e.primitive.name == "dot_general"
+                  and any(v.aval.shape == (chunk, chunk) for v in e.invars)]
+        assert prefix
+        for e in prefix:
+            assert e.params["precision"] == (lax.Precision.HIGHEST,
+                                             lax.Precision.HIGHEST)
 
 
 def test_ssd_initial_state_continuation():
