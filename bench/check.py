@@ -18,6 +18,13 @@ written out from the optimizer's published rule. It keeps each parameter
 in the type the configuration serves it in. It runs once the program's
 state is freed, on one chip.
 
+Because the reference sums the gradient one row at a time, a family's
+``loss`` has to be a mean of per-row terms: the loss of the batch is then
+the mean of each row's loss, whatever rows it is computed with. An expert
+layer that routes with capacity drops, or a load-balance loss taken over
+the whole batch, makes one row's loss depend on the other rows and cannot
+be compared. An auxiliary term taken per sequence can.
+
 Three numbers are compared, each a relative gap:
 
 - ``loss_gap``: the largest |program - reference| / reference over the

@@ -125,7 +125,7 @@ def run(cell, args, devices, peaks, t_start: float, age0: float) -> int:
         cell=cell, seed=args.seed, chips=cell.chips,
         peak=peaks[devices[0].device_kind],
         flops_per_step=flops.train_flops_per_step(
-            cell.model, wl["global_batch"], wl["seq_len"]))
+            cell.config, wl["global_batch"], wl["seq_len"]))
     trace_dir = None
     if args.trace:
         trace_dir = os.path.join(spec.BENCH, "traces", cell.name)

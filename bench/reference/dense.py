@@ -22,9 +22,31 @@ import jax.numpy as jnp
 from .numerics import cross_entropy, normal, rmsnorm
 
 
+TINY = dict(name="dense-tiny", arch_type="dense", num_layers=2,
+            d_model=128, vocab_size=512, num_heads=4, num_kv_heads=2,
+            head_dim=32, d_ff=256, activation="swiglu", qkv_bias=True,
+            rope_theta=10000.0, rope_mode="standard",
+            norm_eps=1.5625e-07, tie_embeddings=False,
+            dtype="bfloat16", remat=True, scan_layers=True)
+
+
 def _dims(m: Dict):
+    if m.get("activation", "swiglu") != "swiglu":
+        raise ValueError(f"dense reference is SwiGLU only, not "
+                         f"{m['activation']!r}")
     d, H, KV = m["d_model"], m["num_heads"], m["num_kv_heads"]
     return d, H, KV, m.get("head_dim") or d // H, m["d_ff"]
+
+
+def macs_per_token(m: Dict, seq: int) -> float:
+    """Forward multiply-adds per token (``bench.flops``' rules). Per layer:
+    the q, k, v and output projections, then (S + 1) / 2 keys on average
+    for q.k and again for p.v, for each of the heads; the SwiGLU MLP's
+    three d x d_ff matrices. Then the output head, d x vocab."""
+    d, h, kv, hd, f = _dims(m)
+    proj = d * (h + 2 * kv) * hd + h * hd * d
+    mix = 2 * h * hd * (seq + 1) / 2
+    return m["num_layers"] * (proj + mix + 3 * d * f) + d * m["vocab_size"]
 
 
 def served_dtypes(m: Dict) -> Dict:

@@ -22,12 +22,35 @@ from .numerics import cross_entropy, normal, rmsnorm, uniform
 
 BLOCK = 64   # SSD block length of the reference
 
+TINY = dict(name="mamba2-tiny", arch_type="ssm", num_layers=2,
+            d_model=128, vocab_size=512, d_ff=0, rope_mode="none",
+            ssm_state=16, ssm_expand=2, ssm_head_dim=32, ssm_chunk=16,
+            tie_embeddings=True, norm_eps=1e-5, dtype="bfloat16",
+            remat=True, scan_layers=True)
+
 
 def _dims(m: Dict):
     d = m["d_model"]
     di = m.get("ssm_expand", 2) * d
     n, p = m["ssm_state"], m.get("ssm_head_dim", 64)
     return d, di, n, di // p, p, m.get("ssm_conv", 4)
+
+
+def macs_per_token(m: Dict, seq: int) -> float:
+    """Forward multiply-adds per token (``bench.flops``' rules). Per layer:
+    the input projection d -> (2 di + 2 n + h), the depthwise causal
+    convolution (K taps on di + 2 n channels), the output projection
+    di -> d, and the SSD scan with chunk length Q: (Q + 1) / 2 positions
+    for C.B and for applying it to the h x p inputs within a chunk, h p n
+    to write each token into the chunk state, and h p n to read the state
+    back out. Then the tied output head, d x vocab. Independent of the
+    sequence length."""
+    d, di, n, h, p, k = _dims(m)
+    q = m.get("ssm_chunk", 128)
+    proj = d * (2 * di + 2 * n + h) + di * d
+    conv = k * (di + 2 * n)
+    ssd = (q + 1) / 2 * n + (q + 1) / 2 * h * p + 2 * h * p * n
+    return m["num_layers"] * (proj + conv + ssd) + d * m["vocab_size"]
 
 
 def served_dtypes(m: Dict) -> Dict:

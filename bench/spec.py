@@ -4,7 +4,10 @@ Every configuration, traffic mix, limit set and metric reader is a file of
 its own, found by the name that ``BENCHMARK.json`` gives it:
 
 - ``bench/configs/<config>.json``: the model's sizes as run, its source and
-  cuts, and the family of its plain reference (``bench/reference/``);
+  cuts, and the family of its plain reference under ``reference``;
+- ``bench/reference/<family>.py``: everything that depends on the model
+  family: the plain reference, the FLOP count and the CPU test size
+  (``bench/reference/__init__.py`` lists them);
 - ``bench/workloads/<traffic>.json``: batch, sequence length, data
   parallelism, grad-sync mode and optimizer of the training job;
 - ``bench/limits/<cell>.json``: the limit of each number that decides
@@ -12,7 +15,8 @@ its own, found by the name that ``BENCHMARK.json`` gives it:
 - ``bench/metrics/<metric>.py``: the reader of each metric, ``read(run)``.
 
 A later change adds a cell, a configuration or a metric by adding such
-files and entries.
+files and entries; a configuration of a new model family brings one new
+file under ``bench/reference/`` besides.
 """
 from __future__ import annotations
 
@@ -54,6 +58,12 @@ def peaks() -> Dict[str, Dict]:
 
 def benchmark(root: str = ROOT) -> Dict:
     return load_json(os.path.join(root, "BENCHMARK.json"))
+
+
+def cell_names(chips: int) -> List[str]:
+    """The cells of ``BENCHMARK.json`` that ask for ``chips`` chips."""
+    return [w["name"] for w in benchmark()["workloads"]
+            if w["chips"] == chips]
 
 
 def applies(metric: Dict, cell: str, e2e: List[Dict]) -> bool:

@@ -19,11 +19,6 @@ from bench import spec
 V5E_HBM = 16 * 2 ** 30
 
 
-def _one_chip_cells():
-    return [w["name"] for w in spec.benchmark()["workloads"]
-            if w["chips"] == 1]
-
-
 @pytest.fixture(scope="module")
 def topo():
     from jax.experimental import topologies
@@ -75,7 +70,7 @@ def _compiled_step(cell, devices):
         return step.lower(params, opt, batch).compile()
 
 
-@pytest.mark.parametrize("name", _one_chip_cells())
+@pytest.mark.parametrize("name", spec.cell_names(1))
 def test_cell_step_fits_one_v5e(topo, name):
     cell = spec.cell(name)
     compiled = _compiled_step(cell, topo.devices)

@@ -4,10 +4,10 @@ the program's place, against the float32 reference, on the CPU at the
 sizes of ``tiny`` and with each cell's own limits."""
 import pytest
 
-from bench import check
+from bench import check, spec
 from bench.tests import tiny
 
-CELLS = ["mamba2-130m.b8x2048.1chip", "glm4-9b-3l.b4x2048.1chip"]
+CELLS = spec.cell_names(1)
 
 
 @pytest.mark.parametrize("name", CELLS)

@@ -10,10 +10,10 @@ import sys
 
 import pytest
 
+from bench import spec
 from bench.tests import tiny
 
 ONE = "mamba2-130m.b8x2048.1chip"
-DENSE = "glm4-9b-3l.b4x2048.1chip"
 FOUR = "mamba2-130m.dp4-canary.b8x2048.4chip"
 
 
@@ -36,7 +36,7 @@ def _break_step(monkeypatch, how):
     monkeypatch.setattr(trainer_mod, "make_train_step", make)
 
 
-@pytest.mark.parametrize("name", [ONE, DENSE])
+@pytest.mark.parametrize("name", spec.cell_names(1))
 def test_sound_program_is_correct(name):
     assert tiny.run(tiny.cell(name))["correct"] is True
 

@@ -174,9 +174,9 @@ def test_make_batch_leaves_a_host_span(tmp_path):
     from repro.data import DataConfig
     from repro.models import ModelConfig
     from repro.train import TrainConfig, Trainer, TrainerConfig
-    from bench.tests.tiny import MODELS
+    from bench.reference import dense
 
-    model = ModelConfig(**MODELS["dense"])
+    model = ModelConfig(**dense.TINY)
     cfg = TrainerConfig(train=TrainConfig(model=model),
                         data=DataConfig(vocab_size=model.vocab_size,
                                         global_batch=2, seq_len=16),

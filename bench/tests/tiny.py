@@ -3,31 +3,25 @@ cell's family, traffic shape and limits, with small widths."""
 import argparse
 import io
 import json
+import pkgutil
 import time
 from contextlib import redirect_stdout
 
-from bench import spec
+from bench import reference, spec
+from bench.reference import family
 
-MODELS = {
-    "mamba2": dict(name="mamba2-tiny", arch_type="ssm", num_layers=2,
-                   d_model=128, vocab_size=512, d_ff=0, rope_mode="none",
-                   ssm_state=16, ssm_expand=2, ssm_head_dim=32, ssm_chunk=16,
-                   tie_embeddings=True, norm_eps=1e-5, dtype="bfloat16",
-                   remat=True, scan_layers=True),
-    "dense": dict(name="dense-tiny", arch_type="dense", num_layers=2,
-                  d_model=128, vocab_size=512, num_heads=4, num_kv_heads=2,
-                  head_dim=32, d_ff=256, activation="swiglu", qkv_bias=True,
-                  rope_theta=10000.0, rope_mode="standard",
-                  norm_eps=1.5625e-07, tie_embeddings=False,
-                  dtype="bfloat16", remat=True, scan_layers=True),
-}
+# Each family's ``TINY`` model by family name, for tests outside the
+# benchmark that build a small model of a family; found, not listed.
+MODELS = {m.name: family(m.name).TINY
+          for m in pkgutil.iter_modules(reference.__path__)
+          if hasattr(family(m.name), "TINY")}
 
 
 def cell(name: str, chips: int = None) -> spec.Cell:
-    """Cell ``name`` of the benchmark, its model cut to ``MODELS``' size
-    and its sequences to 128 tokens."""
+    """Cell ``name`` of the benchmark, its model cut to its family's
+    ``TINY`` size and its sequences to 128 tokens."""
     real = spec.cell(name)
-    config = dict(real.config, model=MODELS[real.config["reference"]])
+    config = dict(real.config, model=family(real.config["reference"]).TINY)
     workload = dict(real.workload, seq_len=128)
     return spec.Cell(name=name, chips=chips or real.chips, config=config,
                      workload=workload, limits=real.limits,
